@@ -14,6 +14,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.utils.compile_cache import use_compile_cache
+
 
 def _emit(name: str, us: float, derived) -> None:
     print(f"{name},{us:.1f},{derived}")
@@ -205,6 +207,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-fast wiring check (used by tier-1 tests)")
     args = ap.parse_args()
+    use_compile_cache()
 
     only = set(args.only.split(","))
     rounds = args.rounds

@@ -43,6 +43,7 @@ sys.path.insert(0, "src")
 from repro.configs import get_config
 from repro.configs.base import AdversaryConfig, FLConfig, PersonalizeConfig
 from repro.core.executor import run_experiment
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -67,6 +68,7 @@ def main() -> None:
                     help="post-global per-client fine-tune stage "
                          "(FLConfig.personalize.mode)")
     args = ap.parse_args()
+    use_compile_cache()
     cfg = get_config("fedsr-mlp")
     adv = (AdversaryConfig() if args.attack == "none"
            else AdversaryConfig(frac=0.2, kind=args.attack))

@@ -110,6 +110,10 @@ class ExperimentResult:
                                             # host (K, ...) stacked arena of
                                             # per-client fine-tuned params
                                             # (feeds serve.fleet routing)
+    dispatches: int = 0                     # the training rounds' compiled
+                                            # calls (LocalTrainer.dispatches;
+                                            # one per block under the fused
+                                            # engine)
 
     @property
     def overlap_fraction(self) -> float:
@@ -345,7 +349,8 @@ def run_experiment(
                                 None if preport is None
                                 else preport.global_client_accuracy),
                             personalized_fleet=(
-                                None if preport is None else preport.fleet))
+                                None if preport is None else preport.fleet),
+                            dispatches=trainer.dispatches)
 
 
 # ---------------------------------------------------------------------------

@@ -98,6 +98,12 @@ def _h2d_nbytes(a) -> int:
     return a.size * min(a.dtype.itemsize, 4)
 
 
+def _put(tree, sharding):
+    """Place every leaf straight onto ``sharding``: host arrays go to each
+    device's shard directly, never through one committed device first."""
+    return jax.tree.map(lambda x: jax.device_put(x, sharding), tree)
+
+
 def _donation_supported() -> bool:
     """Buffer donation is a no-op (with a warning) on the CPU backend; only
     request it where XLA can actually alias the update in place."""
@@ -698,16 +704,16 @@ class LocalTrainer:
         if dscale is not None:
             dscale = jnp.asarray(dscale, jnp.float32)
         if mesh is not None:
-            put, data_s, shard, repl = self._mesh_placement(
+            data_s, shard, repl = self._mesh_placement(
                 mesh, data_axis, valid.shape[0], hop_leading=False)
-            params = put(params, repl if broadcast else shard)
-            batches = put(batches, data_s)
-            valid = put(valid, data_s)
+            params = _put(params, repl if broadcast else shard)
+            batches = _put(batches, data_s)
+            valid = _put(valid, data_s)
             agg, agg_gw, dscale, dref = (
-                x if x is None else put(x, repl)
+                x if x is None else _put(x, repl)
                 for x in (agg, agg_gw, dscale, dref))
             extras = tuple(
-                put(e, shard if s else repl)
+                _put(e, shard if s else repl)
                 for e, s in zip(extras, self._EXTRA_STACKED[variant]))
         head = self._head(agg, agg_gw, dscale, dref)
         return fam(params, batches, valid, jnp.asarray(lr, jnp.float32),
@@ -727,10 +733,10 @@ class LocalTrainer:
     @staticmethod
     def _mesh_placement(mesh, data_axis: str, C: int, hop_leading: bool):
         """NamedSharding placement shared by the sharded and fused engines:
-        a ``put`` helper plus the (per-visit data, client-stacked,
-        replicated) shardings. Per-visit data shards its C axis along
-        ``data_axis`` — with ``hop_leading``, after a leading hop axis —
-        and C must divide the mesh axis (callers ghost-pad)."""
+        the (per-visit data, client-stacked, replicated) shardings.
+        Per-visit data shards its C axis along ``data_axis`` — with
+        ``hop_leading``, after a leading hop axis — and C must divide the
+        mesh axis (callers ghost-pad)."""
         n_shards = mesh.shape[data_axis]
         if C % n_shards != 0:
             raise ValueError(
@@ -741,12 +747,7 @@ class LocalTrainer:
         data_s = NamedSharding(mesh, PartitionSpec(*lead))
         shard = NamedSharding(mesh, PartitionSpec(data_axis))
         repl = NamedSharding(mesh, PartitionSpec())
-
-        def put(tree, sharding):
-            return jax.tree.map(
-                lambda x: jax.device_put(jnp.asarray(x), sharding), tree)
-
-        return put, data_s, shard, repl
+        return data_s, shard, repl
 
     # ------------------------------------------------------------------
     def train_many_fused(
@@ -819,16 +820,16 @@ class LocalTrainer:
         if dscale is not None:
             dscale = jnp.asarray(dscale, jnp.float32)
         if mesh is not None:
-            put, hop_s, shard, repl = self._mesh_placement(
+            hop_s, shard, repl = self._mesh_placement(
                 mesh, data_axis, valid.shape[1], hop_leading=True)
-            params = put(params, repl if broadcast else shard)
-            rows, plans, valid = (put(x, hop_s)
+            params = _put(params, repl if broadcast else shard)
+            rows, plans, valid = (_put(x, hop_s)
                                   for x in (rows, plans, valid))
             agg, agg_gw, dscale, dref = (
-                x if x is None else put(x, repl)
+                x if x is None else _put(x, repl)
                 for x in (agg, agg_gw, dscale, dref))
             extras = tuple(
-                put(e, shard if s else repl)
+                _put(e, shard if s else repl)
                 for e, s in zip(extras, self._EXTRA_STACKED[variant]))
         head = self._head(agg, agg_gw, dscale, dref)
         return fam(params, plane.images, plane.labels, plane.offsets,
@@ -1041,22 +1042,17 @@ class LocalTrainer:
                     f"schedule lane axis C={C} must be a multiple of mesh "
                     f"axis {data_axis!r}={mesh.shape[data_axis]}")
             repl = NamedSharding(mesh, PartitionSpec())
-
-            def put(tree, sharding):
-                return jax.tree.map(
-                    lambda x: jax.device_put(jnp.asarray(x), sharding), tree)
-
             placed = {}
             for k, v in xs.items():
                 lead = self._SCHED_LEAD[k]
                 if lead is None:
-                    placed[k] = put(v, repl)
+                    placed[k] = _put(v, repl)
                 else:
                     spec = PartitionSpec(*([None] * lead + [data_axis]))
-                    placed[k] = put(v, NamedSharding(mesh, spec))
+                    placed[k] = _put(v, NamedSharding(mesh, spec))
             xs = placed
-            params = put(params, repl)
-            carry = put(carry, repl)
+            params = _put(params, repl)
+            carry = _put(carry, repl)
         else:
             xs = {k: jnp.asarray(v) for k, v in xs.items()}
         dpk = () if self._dp is None else (self._next_dp_key(),)

@@ -5,8 +5,10 @@
 #   decode_attention/ flash-decoding over long KV caches (serve_step)
 #   ssd_scan/         Mamba2 SSD chunked scan (sequential-chunk grid + VMEM state)
 #   fused_sgd/        fused momentum-SGD update (the FL ring-hop inner update)
-from jax.experimental.pallas import tpu as _pltpu
+import jax
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-tpu_compiler_params = getattr(_pltpu, "CompilerParams", None) \
-    or _pltpu.TPUCompilerParams
+
+def default_interpret() -> bool:
+    """Interpret mode is for the CPU backend only, where the tests run. Any
+    other backend gets the compiled kernel, or the compiler's error."""
+    return jax.default_backend() == "cpu"

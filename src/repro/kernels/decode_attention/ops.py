@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.decode_attention.kernel import decode_attention_bkgd
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -27,7 +24,7 @@ def decode_attention(
     interpret: bool | None = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     b, _, h, hd = q.shape
     kv = k_cache.shape[2]
     g = h // kv

@@ -5,11 +5,8 @@ import functools
 
 import jax
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -28,7 +25,7 @@ def flash_attention(
     interpret: bool | None = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     out = flash_attention_bhsd(
         q.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),

@@ -1,4 +1,4 @@
-"""Jit'd public wrapper: arbitrary-shape params -> padded flat tiles."""
+"""Jit'd public wrapper: arbitrary-shape params -> padded (rows, 128) tiles."""
 from __future__ import annotations
 
 import functools
@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fused_sgd.kernel import BLOCK, fused_sgd_flat
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels import default_interpret
+from repro.kernels.fused_sgd.kernel import BLOCK, LANES, fused_sgd_2d
 
 
 @functools.partial(
@@ -27,22 +24,27 @@ def fused_sgd_update(
     block: int = BLOCK,
     interpret: bool | None = None,
 ):
-    """Returns (new_p, new_m) for one parameter tensor of any shape."""
+    """Returns (new_p, new_m) for one parameter tensor of any shape.
+    ``block`` is the tile size in elements, rounded up to whole 128-lane
+    rows."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     shape = p.shape
     n = p.size
-    pad = (-n) % block
-    def flat(x):
-        return jnp.pad(x.reshape(-1), (0, pad))
+    block_rows = -(-block // LANES)
+    tile = block_rows * LANES
+    pad = (-n) % tile
 
-    lr_arr = jnp.asarray(lr, p.dtype).reshape(1)
-    p_new, m_new = fused_sgd_flat(
-        flat(p), flat(g), flat(m), lr_arr,
-        momentum=momentum, nesterov=nesterov, block=block, interpret=interpret,
+    def tiles(x):
+        return jnp.pad(x.reshape(-1), (0, pad)).reshape(-1, LANES)
+
+    p_new, m_new = fused_sgd_2d(
+        tiles(p), tiles(g), tiles(m), lr,
+        momentum=momentum, nesterov=nesterov, block_rows=block_rows,
+        interpret=interpret,
     )
 
-    def unflat(x):
-        return x[:n].reshape(shape)
+    def untile(x):
+        return x.reshape(-1)[:n].reshape(shape)
 
-    return unflat(p_new), unflat(m_new)
+    return untile(p_new), untile(m_new)

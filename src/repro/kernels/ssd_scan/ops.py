@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan_bhcqp
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -25,7 +22,7 @@ def ssd_scan(
     interpret: bool | None = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     bsz, l, h, p = x.shape
     g, n = b_mat.shape[2], b_mat.shape[3]
     rep = h // g

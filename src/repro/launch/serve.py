@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.configs.registry import get_smoke_config
 from repro.models.transformer import decode_step, init_cache, init_model
+from repro.utils.compile_cache import use_compile_cache
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -135,6 +136,7 @@ def main() -> None:
                          "only each batch's cohort (fleets larger than "
                          "device memory)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.fleet > 0:
         _serve_fleet(args)

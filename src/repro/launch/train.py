@@ -25,6 +25,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import fl_stack, make_train_step
 from repro.models.transformer import init_model, model_specs
 from repro.nn.module import param_count
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import MetricLogger
 
 
@@ -122,6 +123,7 @@ def main() -> None:
                     help="fused Pallas momentum update (see kernels/fused_sgd)")
     ap.add_argument("--log", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.arch == "fedsr-lm-100m":
         cfg = lm_100m_config()

@@ -4,8 +4,8 @@ optax-like stateless API: ``init(params) -> state``,
 ``update(grads, state, params, lr) -> (new_params, new_state)``.
 
 The parameter update itself is delegated to the fused Pallas kernel
-(`repro.kernels.fused_sgd`) when ``fused=True`` and falls back to pure jnp
-otherwise; both paths are bitwise-checked in tests.
+(`repro.kernels.fused_sgd`) when ``fused=True`` and is pure jnp otherwise;
+both paths are bitwise-checked in tests.
 """
 from __future__ import annotations
 

@@ -171,17 +171,53 @@ def test_fused_sgd_zero_momentum_is_plain_sgd():
                                rtol=1e-6)
 
 
+def _pallas_operand_shapes(fn, *args):
+    """Shapes of every operand of the (single) pallas_call ``fn`` traces to,
+    found through the nested jit jaxprs."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append([v.aval.shape for v in eqn.invars])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(found) == 1, found
+    return found[0]
+
+
+def _assert_tiled(shapes, lead, n, block):
+    """lr is one SMEM scalar; p/g/m are (..., rows, 128) tiles covering
+    ``n`` elements in whole ``block``-element tiles."""
+    lr, *tiles = shapes
+    assert lr == (1,), shapes
+    rows = -(-block // 128)
+    for s in tiles:
+        assert s[:-2] == lead and s[-1] == 128, shapes
+        assert s[-2] % rows == 0 and s[-2] * 128 >= n, shapes
+
+
 @pytest.mark.parametrize("n,block", [
     (1, 256),             # single element, whole tile is pad
     (255, 256), (257, 256),    # one short / one past the tile boundary
     (1023, 1024), (4097, 1024),
+    (130, 200),           # tile not a whole number of 128-lane rows
     (199_210, 65_536),    # the paper MLP's raveled parameter count
+    (319_178, 65_536),    # the paper CNN's raveled parameter count
 ])
 def test_fused_sgd_odd_tails(n, block):
     """fp32 parity on sizes that never divide the tile — the pad/unpad path
-    of the flat-parameter update used by LocalTrainer(use_fused_sgd)."""
+    of the flat-parameter update used by LocalTrainer(use_fused_sgd) —
+    through the (rows, 128) tiling the TPU compiler accepts."""
     p, g, m = arr(n), arr(n), arr(n)
-    pn, mn = fused_sgd_update(p, g, m, lr=0.02, momentum=0.9, block=block)
+
+    def upd(p, g, m):
+        return fused_sgd_update(p, g, m, lr=0.02, momentum=0.9, block=block)
+
+    _assert_tiled(_pallas_operand_shapes(upd, p, g, m), (), n, block)
+    pn, mn = upd(p, g, m)
     pr, mr = sgd_reference(p, g, m, 0.02, momentum=0.9)
     np.testing.assert_allclose(np.asarray(pn), np.asarray(pr),
                                rtol=1e-5, atol=1e-7)
@@ -189,13 +225,18 @@ def test_fused_sgd_odd_tails(n, block):
                                rtol=1e-5, atol=1e-7)
 
 
-def test_fused_sgd_under_vmap():
+@pytest.mark.parametrize("C,n,block", [
+    (4, 300, 256),
+    (25, 4097, 1024),         # 25 lanes: one Table IV edge ring per lane
+    (2, 199_210, 65_536),     # the paper MLP at the default tile
+])
+def test_fused_sgd_under_vmap(C, n, block):
     """The launch path vmaps the client update over the FL stack; the fused
-    kernel must batch correctly."""
-    C, n = 4, 300
+    kernel must batch correctly, as (C, rows, 128) tiles."""
     p, g, m = arr(C, n), arr(C, n), arr(C, n)
     fn = jax.vmap(lambda p, g, m: fused_sgd_update(
-        p, g, m, lr=0.05, momentum=0.5, block=256))
+        p, g, m, lr=0.05, momentum=0.5, block=block))
+    _assert_tiled(_pallas_operand_shapes(fn, p, g, m), (C,), n, block)
     pn, mn = fn(p, g, m)
     pr, mr = sgd_reference(p, g, m, 0.05, momentum=0.5)
     np.testing.assert_allclose(np.asarray(pn), np.asarray(pr),
