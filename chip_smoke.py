@@ -19,7 +19,9 @@ head-personalized fleet through ``FleetClassifier``, 256 requests in one
 batch that reaches every one of the 100 clients, against ``loop_classify``
 under float32 precision.
 
-Every phase prints one JSON line. The last line of stdout is
+Every phase prints one JSON line; its ``compile_s`` is the seconds of the
+backend compiles (cache loads included) that the program's span log
+counted (``repro.utils.trace``). The last line of stdout is
 ``{"ok": true, "device": {"platform", "kind", "count"}}``. Without a TPU,
 or when any check fails, the script exits non-zero and prints no result.
 Nothing here is a metric: the times are single bring-up readings.
@@ -45,6 +47,7 @@ from repro.configs.base import FLConfig, PersonalizeConfig
 from repro.core.executor import run_experiment
 from repro.data.synthetic import make_task
 from repro.serve.fleet import FleetClassifier, FleetParams, loop_classify
+from repro.utils import trace
 from repro.utils.compile_cache import use_compile_cache
 
 # Largest deviations admitted, each relative to the reference's largest
@@ -70,20 +73,9 @@ HEAD = PersonalizeConfig(epochs=3, lr=0.02, mode="head")
 NO_PERSONALIZE = PersonalizeConfig()
 
 
-class CompileClock:
-    """Sums JAX's trace, lowering and backend-compile durations."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event in self.EVENTS:
-            self.seconds += duration
+def compile_s(spans: dict) -> float:
+    """Backend-compile seconds in a span-log snapshot, over every span."""
+    return sum(c["seconds"] for c in spans["compiles"].values())
 
 
 def rel_dev(x, ref) -> float:
@@ -122,7 +114,7 @@ def fedsr(engine: str, rounds: int, **overrides) -> FLConfig:
                     **{**TABLE4, **overrides})
 
 
-def train_phase(model: str, task: str, clock: CompileClock, *,
+def train_phase(model: str, task: str, *,
                 rounds: int = 4, eval_every: int = 2,
                 personalize: PersonalizeConfig = NO_PERSONALIZE,
                 task_kwargs=None, **overrides):
@@ -139,9 +131,7 @@ def train_phase(model: str, task: str, clock: CompileClock, *,
             task=task, model_cfg=cfg, fl=fedsr(engine, rounds, **kw),
             eval_every=eval_every, train=train, test=test)
 
-    c0 = clock.seconds
     res = run("fused", personalize=personalize, **overrides)
-    compile_s = clock.seconds - c0
     with jax.default_matmul_precision("float32"):
         ref = run("sequential", **overrides)
         res32 = run("fused", **overrides)
@@ -149,7 +139,7 @@ def train_phase(model: str, task: str, clock: CompileClock, *,
                   for r in (res, res32))
     flat, _ = ravel_pytree(res.final_model)
     emit({"phase": f"train/{model}", "params": int(flat.size),
-          "compile_s": compile_s,
+          "compile_s": compile_s(res.spans),
           "first_block_s": res.history[0].seconds,
           "warm_block_s": res.history[-1].seconds,
           "rounds_per_block": res.history[-1].rounds,
@@ -170,7 +160,7 @@ def train_phase(model: str, task: str, clock: CompileClock, *,
     return res, test
 
 
-def serve_phase(model: str, fleet_arena, test, clock: CompileClock, *,
+def serve_phase(model: str, fleet_arena, test, *,
                 requests: int = 256, seed: int = 0) -> None:
     """Phase 2: one batch of ``requests`` routed over every client of the
     personalized fleet through ``FleetClassifier``, against the per-model
@@ -183,11 +173,11 @@ def serve_phase(model: str, fleet_arena, test, clock: CompileClock, *,
     lanes = rng.permutation(np.arange(requests) % fleet.num_clients)
     images = test.images[rng.integers(0, len(test), requests)]
     clf = FleetClassifier(cfg)
-    c0 = clock.seconds
+    c0 = compile_s(trace.snapshot())
     t0 = time.perf_counter()
     jax.block_until_ready(clf(fleet, lanes, images))
     first_s = time.perf_counter() - t0
-    compile_s = clock.seconds - c0
+    first_compile_s = compile_s(trace.snapshot()) - c0
     t0 = time.perf_counter()
     logits = jax.block_until_ready(clf(fleet, lanes, images))
     warm_s = time.perf_counter() - t0
@@ -199,7 +189,7 @@ def serve_phase(model: str, fleet_arena, test, clock: CompileClock, *,
                           == np.argmax(np.asarray(ref), -1)))
     emit({"phase": f"serve/{model}", "requests": requests,
           "distinct_lanes": int(np.unique(lanes).size),
-          "compile_s": compile_s, "first_batch_s": first_s,
+          "compile_s": first_compile_s, "first_batch_s": first_s,
           "warm_batch_s": warm_s, "max_rel_dev": dev,
           "max_rel_dev_float32": dev32, "argmax_agreement": agree,
           "peak_bytes_in_use": peak_bytes()})
@@ -210,7 +200,7 @@ def serve_phase(model: str, fleet_arena, test, clock: CompileClock, *,
     check(dev32 <= SERVE_F32_TOL, f"serve: float32 deviation {dev32}")
 
 
-def mesh_phase(clock: CompileClock, *, rounds: int = 4, eval_every: int = 2,
+def mesh_phase(*, rounds: int = 4, eval_every: int = 2,
                task_kwargs=None, **overrides) -> None:
     """The lane axis and the data plane over every visible device
     (``mesh_data_axis="data"``) against the same run on one device, at the
@@ -225,9 +215,7 @@ def mesh_phase(clock: CompileClock, *, rounds: int = 4, eval_every: int = 2,
             fl=fedsr("fused", rounds, **overrides, **kw),
             eval_every=eval_every, train=train, test=test)
 
-    c0 = clock.seconds
     mesh = run(mesh_data_axis="data")
-    compile_s = clock.seconds - c0
     per_device = {str(d.id): peak_bytes(d) for d in jax.devices()}
     one = run()
     with jax.default_matmul_precision("float32"):
@@ -235,7 +223,7 @@ def mesh_phase(clock: CompileClock, *, rounds: int = 4, eval_every: int = 2,
     dev = rel_dev(mesh.final_model, one.final_model)
     dev32 = rel_dev(mesh32.final_model, one32.final_model)
     emit({"phase": "mesh/fedsr-cnn", "devices": len(jax.devices()),
-          "compile_s": compile_s,
+          "compile_s": compile_s(mesh.spans),
           "warm_block_s": mesh.history[-1].seconds,
           "one_chip_warm_block_s": one.history[-1].seconds,
           "accuracy": [h.accuracy for h in mesh.history],
@@ -265,14 +253,14 @@ def main(argv=None) -> int:
               "device(s)", file=sys.stderr)
         return 1
     use_compile_cache()
-    clock = CompileClock()
+    trace.watch_host()
     if args.chips == 4:
-        mesh_phase(clock)
+        mesh_phase()
     else:
-        cnn, test = train_phase("fedsr-cnn", "cifar10_like", clock,
+        cnn, test = train_phase("fedsr-cnn", "cifar10_like",
                                 personalize=HEAD)
-        train_phase("fedsr-mlp", "mnist_like", clock)
-        serve_phase("fedsr-cnn", cnn.personalized_fleet, test, clock)
+        train_phase("fedsr-mlp", "mnist_like")
+        serve_phase("fedsr-cnn", cnn.personalized_fleet, test)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

@@ -74,6 +74,7 @@ from repro.core.state import (
 )
 from repro.core.topology import assign_edges, clusters_of, sample_ring
 from repro.data.pipeline import ClientData, plan_epoch_indices
+from repro.utils import trace
 from repro.utils.tree import tree_bytes, tree_stack, tree_zeros_like
 
 Pytree = Any
@@ -106,6 +107,7 @@ class _Planner:
         self.privacy = (PrivacyLedger(fl.dp_noise_mult, fl.dp_delta)
                         if fl.dp_clip > 0 else None)
         self.residency = ResidencyMeter()
+        trace.watch_host()
         self._transient_state_bytes = 0     # the in-flight block's staged
                                             # carries while the next block's
                                             # are eagerly staged (pipeline)
@@ -154,19 +156,22 @@ class _Planner:
         matching ``prefetch_block`` makes both hand-offs) and launch the
         dispatch. Returns as soon as the work is enqueued; the returned
         ``w_glob`` is a device future under the fused engine."""
-        self.ensure_state(state, w_glob)
-        visited = sched.visited()
-        self._stage_state(state, visited)
-        data_bytes = self.engine.stage_data(visited)
-        self.residency.record(data_bytes, self._staged_state_bytes(state))
-        # double-buffered high-water mark: both pipeline arenas at the
-        # hand-off (``stage_pair_nbytes``) plus the previous block's
-        # staged carries if the next block's were eagerly staged while
-        # they were still live
-        self.residency.record_transient(
-            self.engine.stage_pair_nbytes()
-            + self._staged_state_bytes(state) + self._transient_state_bytes)
-        self._transient_state_bytes = 0
+        with trace.span("stage"):
+            self.ensure_state(state, w_glob)
+            visited = sched.visited()
+            self._stage_state(state, visited)
+            data_bytes = self.engine.stage_data(visited)
+            self.residency.record(data_bytes,
+                                  self._staged_state_bytes(state))
+            # double-buffered high-water mark: both pipeline arenas at the
+            # hand-off (``stage_pair_nbytes``) plus the previous block's
+            # staged carries if the next block's were eagerly staged while
+            # they were still live
+            self.residency.record_transient(
+                self.engine.stage_pair_nbytes()
+                + self._staged_state_bytes(state)
+                + self._transient_state_bytes)
+            self._transient_state_bytes = 0
         return self.engine.run_schedule(sched, w_glob, lrs, state,
                                         self.update_state)
 
@@ -176,19 +181,20 @@ class _Planner:
         into the host arena (the ONE device readback of the residency
         protocol — the pipeline's sync point) and apply the block's
         closed-form privacy/comm records."""
-        self._unstage_state(state)
-        if self.privacy is not None:
-            # worst-case client: the ledger advances by each round's max
-            # per-client executed steps (closed-form on the plans)
-            for plan in sched.plans:
-                self.privacy.record(plan_max_client_steps(plan))
-        if meter is not None:
-            for channel, count in sched.comm:
-                meter.record(channel, count)
-            # accumulate round-by-round (NOT a pre-summed block total) so
-            # the float stream is block-size invariant bit-exactly
-            for plan in sched.plans:
-                meter.record_time(plan.sim_seconds)
+        with trace.span("finish"):
+            self._unstage_state(state)
+            if self.privacy is not None:
+                # worst-case client: the ledger advances by each round's
+                # max per-client executed steps (closed-form on the plans)
+                for plan in sched.plans:
+                    self.privacy.record(plan_max_client_steps(plan))
+            if meter is not None:
+                for channel, count in sched.comm:
+                    meter.record(channel, count)
+                # accumulate round-by-round (NOT a pre-summed block total)
+                # so the float stream is block-size invariant bit-exactly
+                for plan in sched.plans:
+                    meter.record_time(plan.sim_seconds)
 
     def prefetch_block(self, sched: Schedule,
                        inflight_visited: np.ndarray, state: Dict) -> None:
@@ -277,12 +283,15 @@ class _Planner:
     def plan_schedule(self, t0: int, n: int, rng: np.random.Generator,
                       state: Dict) -> Schedule:
         """``n`` rounds of plans, drawn in the per-round RNG order."""
-        plans = tuple(self.plan_round(t0 + k, rng, state) for k in range(n))
-        totals: Dict[str, int] = {}
-        for plan in plans:
-            for channel, count in plan.comm:
-                totals[channel] = totals.get(channel, 0) + count
-        return Schedule(plans=plans, comm=tuple(sorted(totals.items())))
+        with trace.span("plan"):
+            plans = tuple(self.plan_round(t0 + k, rng, state)
+                          for k in range(n))
+            totals: Dict[str, int] = {}
+            for plan in plans:
+                for channel, count in plan.comm:
+                    totals[channel] = totals.get(channel, 0) + count
+            return Schedule(plans=plans,
+                            comm=tuple(sorted(totals.items())))
 
     def plan_round(self, t: int, rng: np.random.Generator,
                    state: Dict) -> RoundPlan:
@@ -362,6 +371,13 @@ class _Planner:
         return plan_epoch_indices(self.clients[i], self.fl.batch_size,
                                   self.fl.local_epochs, rng)
 
+    def _cohort_plans(self, ids: List[int],
+                      rng: np.random.Generator) -> Tuple[np.ndarray, ...]:
+        """One batch plan per cohort client, in ``ids`` order."""
+        plans = tuple(self._batch_plan(i, rng) for i in ids)
+        trace.count("plan_draws", len(plans))
+        return plans
+
     def _sample(self, rng: np.random.Generator) -> List[int]:
         k = self.fl.num_devices
         n = max(1, int(round(k * self.fl.participation)))
@@ -386,6 +402,7 @@ class _Planner:
             for lap in range(fl.ring_rounds):
                 for j, i in enumerate(ring):
                     plans[r, lap, j] = self._batch_plan(i, rng)
+        trace.count("plan_draws", len(plans))
         width = max(len(r) for r in rings)
         return tuple(
             Hop(ids=tuple(ring[j] if j < len(ring) else ring[0]
@@ -402,7 +419,7 @@ class FedAvg(_Planner):
 
     def _plan_round(self, t, rng, state):
         ids = self._sample(rng)
-        plans = tuple(self._batch_plan(i, rng) for i in ids)
+        plans = self._cohort_plans(ids, rng)
         shared, stacked = self._extra_specs(ids, state)
         group = VisitGroup(
             hops=(Hop(tuple(ids), plans),), variant=self.variant,
@@ -502,7 +519,7 @@ class Scaffold(_Planner):
 
     def _plan_round(self, t, rng, state):
         ids = self._sample(rng)
-        plans = tuple(self._batch_plan(i, rng) for i in ids)
+        plans = self._cohort_plans(ids, rng)
         group = VisitGroup(
             hops=(Hop(tuple(ids), plans),), variant="scaffold",
             shared_extras={"c_glob": StateRef("c")},
@@ -583,6 +600,7 @@ class HierFAVG(_Planner):
             for r in range(fl.ring_rounds):
                 for i in ids:
                     plans[e, r, i] = self._batch_plan(i, rng)
+        trace.count("plan_draws", len(plans))
         pairs = [(e, i) for e, ids in enumerate(edge_ids) for i in ids]
         lane_w, agg_groups, off = [], [], 0
         for ids in edge_ids:

@@ -30,6 +30,7 @@ from repro.core.engines.batched import BatchedEngine
 from repro.core.plan import Schedule, VisitGroup
 from repro.data.pipeline import DeviceDataPlane, stack_plan_indices
 from repro.data.store import make_store
+from repro.utils import trace
 
 
 class FusedEngine(BatchedEngine):
@@ -124,8 +125,10 @@ class FusedEngine(BatchedEngine):
             return w_glob       # ring_rounds=0: rounds leave w unchanged
         hier = len(plans[0].groups) > 1
         variant = plans[0].groups[0].variant
-        xs = (self._stack_hier_schedule(plans, lrs) if hier
-              else self._stack_cohort_schedule(plans, lrs, variant, state))
+        with trace.span("pack"):
+            xs = (self._stack_hier_schedule(plans, lrs) if hier
+                  else self._stack_cohort_schedule(plans, lrs, variant,
+                                                   state))
         carry = {}
         if variant == "moon":
             carry = {"prev": state["prev"]}

@@ -54,6 +54,7 @@ from repro.data.pipeline import make_clients
 from repro.data.synthetic import Dataset, make_task
 from repro.models.small import classifier_accuracy, init_small_model
 from repro.optim.schedules import cosine_decay
+from repro.utils import trace
 from repro.utils.tree import tree_bytes
 
 Pytree = Any
@@ -114,6 +115,9 @@ class ExperimentResult:
                                             # calls (LocalTrainer.dispatches;
                                             # one per block under the fused
                                             # engine)
+    spans: Dict[str, Any] = dataclasses.field(default_factory=dict)
+                                            # the span log over the run
+                                            # (``utils.trace.snapshot``)
 
     @property
     def overlap_fraction(self) -> float:
@@ -155,6 +159,7 @@ def run_experiment(
     resume: bool = False,
     stop_after: Optional[int] = None,   # simulate interruption after round N
 ) -> ExperimentResult:
+    trace.reset()       # ExperimentResult.spans covers this run
     if train is None or test is None:
         train, test = make_task(task, seed=fl.seed)
     rng = np.random.default_rng(fl.seed)
@@ -226,7 +231,8 @@ def run_experiment(
         the clock (JAX async dispatch would otherwise under-measure the
         block), then record the eval point."""
         nonlocal last_time, last_round, dispatch_t0
-        jax.block_until_ready(acc_dev)
+        with trace.span("eval"):
+            jax.block_until_ready(acc_dev)
         now = time.perf_counter()
         if dispatch_t0 is not None:
             algo.residency.record_dispatch(now - dispatch_t0)
@@ -350,7 +356,8 @@ def run_experiment(
                                 else preport.global_client_accuracy),
                             personalized_fleet=(
                                 None if preport is None else preport.fleet),
-                            dispatches=trainer.dispatches)
+                            dispatches=trainer.dispatches,
+                            spans=trace.snapshot())
 
 
 # ---------------------------------------------------------------------------
@@ -391,17 +398,19 @@ def _save_checkpoint(ckdir: str, w_glob, round_: int, rng_state: Dict,
 
     from repro.checkpoint.io import save as _save
 
-    _os.makedirs(ckdir, exist_ok=True)
-    _save(f"{ckdir}/model.msgpack", w_glob)
-    _save(f"{ckdir}/algo_state.msgpack", _pack_state(state or {}))
-    comm = {f: int(getattr(meter, f)) for f in
-            ("model_bytes", "cloud_up", "cloud_down", "edge_up",
-             "edge_down", "p2p")}
-    comm["sim_seconds"] = float(meter.sim_seconds)
-    with open(f"{ckdir}/state.json", "w") as f:
-        _json.dump({"round": round_, "rng_state": rng_state,
-                    "comm": comm,
-                    "history": [dataclasses.asdict(r) for r in history]}, f)
+    with trace.span("checkpoint"):
+        _os.makedirs(ckdir, exist_ok=True)
+        _save(f"{ckdir}/model.msgpack", w_glob)
+        _save(f"{ckdir}/algo_state.msgpack", _pack_state(state or {}))
+        comm = {f: int(getattr(meter, f)) for f in
+                ("model_bytes", "cloud_up", "cloud_down", "edge_up",
+                 "edge_down", "p2p")}
+        comm["sim_seconds"] = float(meter.sim_seconds)
+        with open(f"{ckdir}/state.json", "w") as f:
+            _json.dump({"round": round_, "rng_state": rng_state,
+                        "comm": comm,
+                        "history": [dataclasses.asdict(r)
+                                    for r in history]}, f)
 
 
 def _restore_checkpoint(ckdir: str):

@@ -65,6 +65,7 @@ never changes; only the array the offsets point into does.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -77,12 +78,29 @@ from repro.configs.base import FLConfig, ModelConfig
 from repro.core.robust import robust_agg
 from repro.data.pipeline import plan_epoch_indices
 from repro.models.small import classifier_loss, small_model_features
+from repro.utils import trace
 from repro.utils.tree import tree_sq_norm, tree_sub
 
 Pytree = Any
 
 # the default (exact eq.-11) reduce spec: (reducer, trim_frac, krum_f)
 _WMEAN = ("weighted_mean", 0.0, 0)
+
+
+def _scoped(name: str):
+    """Trace the function under ``jax.named_scope(name)``: the ops it
+    emits carry ``name`` in their HLO ``op_name`` metadata (profiles,
+    dumps). The computation is unchanged."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+_robust_agg = _scoped("edge_cloud_reduce")(robust_agg)
 
 
 def _expand_mask(ok, x):
@@ -110,6 +128,7 @@ def _donation_supported() -> bool:
     return jax.default_backend() != "cpu"
 
 
+@_scoped("edge_cloud_reduce")
 def _tree_agg(stack, w):
     """Contract the reduction array against a (C, ...) lane stack: a (C,)
     vector yields the single aggregated tree, a (G, C) matrix the (G, ...)
@@ -141,7 +160,7 @@ def _reduce_stack(stack, aggm, gw, rspec):
     a Byzantine-robust order statistic (``core.robust``)."""
     if rspec[0] == "weighted_mean":
         return _tree_agg(stack, aggm)
-    return robust_agg(stack, aggm, gw, rspec[0], rspec[1], rspec[2])
+    return _robust_agg(stack, aggm, gw, rspec[0], rspec[1], rspec[2])
 
 
 def _split_head(rest, dp: bool, mode: str, has_gw: bool, has_dscale: bool,
@@ -223,6 +242,7 @@ def _run_hops(vgrad, update, n_loss_extras, params, images, labels, offsets,
     m = jax.tree.map(jnp.zeros_like, params)
     xs = (flat_rows, flat_ix, flat_ok, reset)
 
+    @_scoped("hop_gather")
     def gather(row_s, ix):
         # fleet row r, sample i -> flat row offsets[r] + i: ONE
         # (C, B)-indexed gather per leaf, so a step reads C*B rows — a
@@ -333,6 +353,7 @@ class LocalTrainer:
         self._dp_ctr = 0            # fold_in counter: one fresh key per
                                     # dispatch (per step for train())
 
+        @_scoped("momentum_update")
         def apply_update(params, m, grads, lr):
             """m = mu*m + g; p = p - lr*m. Elementwise, so the same code
             updates a single client or a client-stacked pytree. Opt-in path:
@@ -350,6 +371,7 @@ class LocalTrainer:
             params = jax.tree.map(lambda p, mi: p - lr * mi, params, m)
             return params, m
 
+        @_scoped("momentum_update")
         def scaffold_update(params, m, grads, lr, c_glob, c_local):
             # SCAFFOLD (Karimireddy et al. 2020): drift-corrected gradient
             # g + c - c_i (momentum-free, as in the paper's Algorithm 1)
@@ -391,6 +413,7 @@ class LocalTrainer:
         #        p' = p - (ok*lr)*m'             (== p - lr*m'  | p)
         #    so an invalid step is a no-op without the extra read/write
         #    passes a jnp.where select would cost (the scan is memory-bound).
+        @_scoped("momentum_update")
         def masked_momentum_update(params, m, grads, lr, ok):
             if fused:
                 # the flat kernel has no per-client lane — fall back to an
@@ -410,6 +433,7 @@ class LocalTrainer:
                 lambda p, mi: p - (_expand_mask(ok, p) * lr) * mi, params, m)
             return params, m
 
+        @_scoped("momentum_update")
         def masked_scaffold_update(params, m, grads, lr, c_glob, c_local, ok):
             # c_glob is ONE unstacked tree (cohort-shared): its (...) leaves
             # broadcast elementwise against the (C, ...) grad/c_local stacks.
@@ -431,7 +455,8 @@ class LocalTrainer:
             # (all static — the default builds today's jaxpr, bit-for-bit).
             n_loss_extras = len(extra_axes)
             dp = dp_many is not None
-            vgrad = jax.vmap(_grad(loss_fn), in_axes=(0, 0) + extra_axes)
+            vgrad = _scoped("local_grad")(
+                jax.vmap(_grad(loss_fn), in_axes=(0, 0) + extra_axes))
 
             @jax.jit
             def many(params, batches, valid, lr, *rest):
@@ -500,7 +525,8 @@ class LocalTrainer:
                             has_dscale=False, has_dref=False):
             n_loss_extras = len(extra_axes)
             dp = dp_many is not None
-            vgrad = jax.vmap(_grad(loss_fn), in_axes=(0, 0) + extra_axes)
+            vgrad = _scoped("local_grad")(
+                jax.vmap(_grad(loss_fn), in_axes=(0, 0) + extra_axes))
 
             def many_hops(params, images, labels, offsets, rows, plans,
                           valid, lr, *rest):
@@ -875,7 +901,8 @@ class LocalTrainer:
         loss_fn, update, n_loss = self._many_spec[variant]
         axes = tuple(0 if stacked else None
                      for stacked in self._EXTRA_STACKED[variant][:n_loss])
-        vgrad = jax.vmap(self._grad(loss_fn), in_axes=(0, 0) + axes)
+        vgrad = _scoped("local_grad")(
+            jax.vmap(self._grad(loss_fn), in_axes=(0, 0) + axes))
         dp_many = self._dp_many
         dp = dp_many is not None
         robust = rspec[0] != "weighted_mean"
@@ -926,12 +953,13 @@ class LocalTrainer:
 
                     def inter(p):
                         if robust:
-                            return robust_agg(p, x["wg"], None, *rspec)
+                            return _robust_agg(p, x["wg"], None, *rspec)
                         return _tree_agg(p, x["wg"])
 
                     def final(p):
                         if robust:
-                            return robust_agg(p, x["wg"], x["gwv"], *rspec)
+                            return _robust_agg(p, x["wg"], x["gwv"],
+                                               *rspec)
                         return _tree_agg(p, x["aggv"])
 
                     E = _tree_bcast(w, x["wg"].shape[0])
@@ -960,7 +988,8 @@ class LocalTrainer:
                     if has_dscale:
                         p = _apply_lane_scale(p, x["dscale"], w)
                     if robust:
-                        w_new = robust_agg(p, x["aggw"], x["aggg"], *rspec)
+                        w_new = _robust_agg(p, x["aggw"], x["aggg"],
+                                            *rspec)
                     else:
                         w_new = _tree_agg(p, x["aggv"])
                     return w_new, update_carry(w, st, x, p)
@@ -1026,7 +1055,9 @@ class LocalTrainer:
         sequential scan); the state carry is replicated (its K + 1 rows
         need not divide the mesh).
         """
-        self.h2d_bytes += sum(np.asarray(v).nbytes for v in xs.values())
+        nbytes = sum(np.asarray(v).nbytes for v in xs.values())
+        self.h2d_bytes += nbytes
+        trace.count("h2d_bytes", nbytes)
         self.dispatches += 1
         rspec = (reducer, float(trim_frac), int(krum_f))
         has_dscale = "dscale" in xs
@@ -1041,23 +1072,26 @@ class LocalTrainer:
                 raise ValueError(
                     f"schedule lane axis C={C} must be a multiple of mesh "
                     f"axis {data_axis!r}={mesh.shape[data_axis]}")
-            repl = NamedSharding(mesh, PartitionSpec())
-            placed = {}
-            for k, v in xs.items():
-                lead = self._SCHED_LEAD[k]
-                if lead is None:
-                    placed[k] = _put(v, repl)
-                else:
-                    spec = PartitionSpec(*([None] * lead + [data_axis]))
-                    placed[k] = _put(v, NamedSharding(mesh, spec))
-            xs = placed
-            params = _put(params, repl)
-            carry = _put(carry, repl)
-        else:
-            xs = {k: jnp.asarray(v) for k, v in xs.items()}
+        with trace.span("put"):
+            if mesh is not None:
+                repl = NamedSharding(mesh, PartitionSpec())
+                placed = {}
+                for k, v in xs.items():
+                    lead = self._SCHED_LEAD[k]
+                    if lead is None:
+                        placed[k] = _put(v, repl)
+                    else:
+                        spec = PartitionSpec(*([None] * lead + [data_axis]))
+                        placed[k] = _put(v, NamedSharding(mesh, spec))
+                xs = placed
+                params = _put(params, repl)
+                carry = _put(carry, repl)
+            else:
+                xs = {k: jnp.asarray(v) for k, v in xs.items()}
         dpk = () if self._dp is None else (self._next_dp_key(),)
-        return fn(params, carry, plane.images, plane.labels, plane.offsets,
-                  xs, *dpk)
+        with trace.span("dispatch"):
+            return fn(params, carry, plane.images, plane.labels,
+                      plane.offsets, xs, *dpk)
 
     # which extras carry a leading client axis (True) vs are cohort-shared
     # single trees (False) — order matches ``_extras``

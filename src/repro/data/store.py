@@ -50,7 +50,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.data.pipeline import ClientData, DeviceDataPlane
-from repro.utils.logging import timed
+from repro.utils.trace import span
 
 
 class ClientStore:
@@ -96,7 +96,7 @@ class DeviceStore(ClientStore):
 
     def arena(self, visited=None) -> DeviceDataPlane:
         if self._plane is None:
-            with timed(lambda s: setattr(
+            with span("stage_data", lambda s: setattr(
                     self, "stage_seconds", self.stage_seconds + s)):
                 self._plane = DeviceDataPlane(
                     self.clients, mesh=self.mesh, data_axis=self.data_axis)
@@ -132,7 +132,7 @@ class _StagedStore(ClientStore):
         before the transfer lands)."""
         import jax
         secs = [0.0]
-        with timed(lambda s: secs.__setitem__(0, s)):
+        with span("stage_data", lambda s: secs.__setitem__(0, s)):
             plane = DeviceDataPlane(
                 self._cohort(visited), mesh=self.mesh,
                 data_axis=self.data_axis, client_ids=visited,

@@ -1,28 +1,12 @@
-"""Tiny structured logger (stdout + optional jsonl file) and the shared
-wall-clock probe used by the pipeline instrumentation."""
+"""Tiny structured logger (stdout + optional jsonl file). Timing spans live
+in ``repro.utils.trace``."""
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import sys
 import time
-from typing import Any, Callable, Iterator, Optional
-
-
-@contextlib.contextmanager
-def timed(on_done: Callable[[float], None]) -> Iterator[None]:
-    """Measure the block's wall time and hand the seconds to ``on_done``.
-
-    The ONE timing idiom of the staging/dispatch instrumentation
-    (``data.store``, ``core.executor``): callers that time device work are
-    responsible for fencing (``jax.block_until_ready``) inside the block —
-    under JAX async dispatch an unfenced timestamp under-measures."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        on_done(time.perf_counter() - t0)
+from typing import Any, Optional
 
 
 class MetricLogger:

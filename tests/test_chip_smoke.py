@@ -36,8 +36,8 @@ def _run_phase(fn, *args, **kwargs):
 @pytest.fixture(scope="module")
 def cnn_phase():
     (res, test), line = _run_phase(
-        cs.train_phase, "fedsr-cnn", "cifar10_like", cs.CompileClock(),
-        personalize=cs.HEAD, **TINY)
+        cs.train_phase, "fedsr-cnn", "cifar10_like", personalize=cs.HEAD,
+        **TINY)
     return res, test, line
 
 
@@ -46,9 +46,9 @@ def test_train_phase_fused_matches_sequential(model, cnn_phase):
     if model == "fedsr-cnn":
         line = cnn_phase[2]
     else:
-        _, line = _run_phase(cs.train_phase, model, "mnist_like",
-                             cs.CompileClock(), **TINY)
+        _, line = _run_phase(cs.train_phase, model, "mnist_like", **TINY)
     assert line["phase"] == f"train/{model}"
+    assert line["compile_s"] > 0                    # the program's count
     assert line["dispatches"] == 2                  # one per eval block
     assert len(line["accuracy"]) == 2
     assert line["max_acc_dev"] <= cs.ACC_TOL
@@ -60,7 +60,7 @@ def test_serve_phase_stacked_matches_loop(cnn_phase):
     res, test, _ = cnn_phase
     assert res.personalized_fleet is not None
     _, line = _run_phase(cs.serve_phase, "fedsr-cnn", res.personalized_fleet,
-                         test, cs.CompileClock(), requests=16)
+                         test, requests=16)
     assert line["requests"] == 16
     assert line["distinct_lanes"] == TINY["num_devices"]
     assert line["max_rel_dev"] <= cs.SERVE_TOL
@@ -71,7 +71,7 @@ def test_mesh_phase_on_four_virtual_devices():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     code = ("import chip_smoke as cs\n"
-            f"cs.mesh_phase(cs.CompileClock(), **{TINY!r})\n")
+            f"cs.mesh_phase(**{TINY!r})\n")
     proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
