@@ -28,11 +28,12 @@ rule through three entry points:
   axis (callers ghost-pad).
 * ``train_many_fused`` — the batched math against a device-resident
   ``DeviceDataPlane``. Per call, only int32 plan arrays cross H2D; the
-  scan body gathers each step's batch from the resident fleet stack with
-  ``jnp.take``. A leading hop axis H runs as an OUTER ``lax.scan``
-  carrying the model stack, so a whole ring lap sequence (R*K visits) is
-  ONE compiled dispatch; the non-broadcast family donates the params stack
-  to the computation (in-place update on accelerator backends).
+  scan body gathers each step's batch of feature rows from the resident
+  fleet stack and reshapes it to the model's input. A leading hop axis H
+  runs as an OUTER ``lax.scan`` carrying the model stack, so a whole ring
+  lap sequence (R*K visits) is ONE compiled dispatch; the non-broadcast
+  family donates the params stack to the computation (in-place update on
+  accelerator backends).
 * ``train_schedule`` — one level further: a whole eval-to-eval BLOCK of
   rounds as one compiled call. A ``lax.scan`` over the round axis carries
   ``(w_glob, algo_state)`` — each round body broadcasts the carried
@@ -60,7 +61,7 @@ raveled parameter vector (``FLConfig.use_fused_sgd``).
 Both fused entry points are store-agnostic (``FLConfig.store``): the
 ``DeviceDataPlane`` they gather from may hold the whole fleet or only a
 block's visited cohort (``data.store.HostStore``) — the plane's offsets
-table is fleet-sized either way, so the traced ``jnp.take`` addressing
+table is fleet-sized either way, so the traced gather's addressing
 never changes; only the array the offsets point into does.
 """
 from __future__ import annotations
@@ -215,8 +216,30 @@ def _make_dp(clip: float, sigma: float, stacked: bool):
     return apply
 
 
+@_scoped("hop_gather")
+def hop_gather(images, labels, offsets, row_s, ix, item_shape):
+    """One hop step's batch from a ``DeviceDataPlane``: lane ``c`` reads
+    fleet row ``row_s[c]``'s samples ``ix[c]`` (C, B) as images
+    ``(C, B) + item_shape`` and labels (C, B).
+
+    Fleet row r, sample i -> flat row ``offsets[r] + i``: ONE (C, B)-indexed
+    gather per array, so a step reads C*B rows — a per-lane take-of-take
+    would materialize (C, N_max, ...) intermediates and all-gather the
+    sharded plane instead. Plans index a client's own ``[0, len)`` and
+    ghost/unvisited lanes map to row 0, so every index is in bounds: a
+    bare gather of contiguous feature rows, with no out-of-bounds fill
+    select fused over the batch (``tests/test_hop_gather.py`` holds the
+    engines to that bound)."""
+    def rows_at(a, i):
+        return a.at[i].get(mode="promise_in_bounds")
+    gidx = rows_at(offsets, row_s)[:, None] + ix
+    x = rows_at(images, gidx)
+    return {"images": x.reshape(x.shape[:2] + item_shape),
+            "labels": rows_at(labels, gidx)}
+
+
 def _run_hops(vgrad, update, n_loss_extras, params, images, labels, offsets,
-              rows, plans, valid, lr, extras, dp=None, key=None):
+              rows, plans, valid, lr, extras, item_shape, dp=None, key=None):
     """The flat H*S-step gathered-SGD scan over one visit group, shared by
     ``train_many_fused`` and the schedule dispatch (``train_schedule``).
 
@@ -228,6 +251,10 @@ def _run_hops(vgrad, update, n_loss_extras, params, images, labels, offsets,
     regime. Instead the momentum carry is zeroed by a per-step reset flag
     wherever a new client visit begins — same math, one flat scan of H*S
     gathered SGD steps. Returns the trained (C, ...) stack.
+
+    ``images`` is the plane's flat (total, D) feature-row array and
+    ``item_shape`` the model's static per-sample input shape; each step's
+    batch comes from ``hop_gather``.
 
     ``dp``/``key`` opt the scan into DP-SGD: the per-step gradient passes
     through the ``_make_dp`` transform with a key split from the scan
@@ -242,15 +269,8 @@ def _run_hops(vgrad, update, n_loss_extras, params, images, labels, offsets,
     m = jax.tree.map(jnp.zeros_like, params)
     xs = (flat_rows, flat_ix, flat_ok, reset)
 
-    @_scoped("hop_gather")
     def gather(row_s, ix):
-        # fleet row r, sample i -> flat row offsets[r] + i: ONE
-        # (C, B)-indexed gather per leaf, so a step reads C*B rows — a
-        # per-lane take-of-take would materialize (C, N_max, ...)
-        # intermediates and all-gather the sharded plane instead
-        gidx = jnp.take(offsets, row_s)[:, None] + ix
-        return {"images": jnp.take(images, gidx, axis=0),
-                "labels": jnp.take(labels, gidx, axis=0)}
+        return hop_gather(images, labels, offsets, row_s, ix, item_shape)
 
     if dp is None:
         def body(carry, x):
@@ -284,6 +304,10 @@ class LocalTrainer:
                  grad_mask: Optional[Pytree] = None):
         self.cfg = cfg
         self.fl = fl
+        # one sample's input shape: the fused engines reshape each step's
+        # gathered (C, B, D) feature rows from the data plane to it
+        self._item_shape = (cfg.image_size, cfg.image_size,
+                            cfg.image_channels)
 
         # ``grad_mask`` freezes parameter subtrees at construction (like
         # DP-SGD, baked so mask-off builds literally today's jaxpr): a
@@ -530,7 +554,7 @@ class LocalTrainer:
 
             def many_hops(params, images, labels, offsets, rows, plans,
                           valid, lr, *rest):
-                # images/labels: flat (total, ...) resident fleet stacks,
+                # images (total, D) / labels (total,): flat resident stacks,
                 # offsets: (K,) first flat row of each client; rows: (H, C)
                 # int32 fleet row of each cohort/ring slot per hop; plans:
                 # (H, C, S, B) int32 sample indices; valid: (H, C, S).
@@ -543,7 +567,8 @@ class LocalTrainer:
                     params = _tree_bcast(params, valid.shape[1])
                 p = _run_hops(vgrad, update, n_loss_extras, params, images,
                               labels, offsets, rows, plans, valid, lr,
-                              extras, dp=dp_many, key=key)
+                              extras, self._item_shape, dp=dp_many,
+                              key=key)
                 if mode == "stack":
                     return p
                 if ds is not None:
@@ -936,7 +961,8 @@ class LocalTrainer:
             def train_group(params, rows, plans, valid, lr, extras, key):
                 return _run_hops(vgrad, update, n_loss, params, images,
                                  labels, offsets, rows, plans, valid, lr,
-                                 extras, dp=dp_many, key=key)
+                                 extras, self._item_shape, dp=dp_many,
+                                 key=key)
 
             if hier:
                 def round_step(w, st, x, key):

@@ -22,6 +22,7 @@ are the engines' materialization step, never called by planners.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -185,9 +186,13 @@ class DeviceDataPlane:
     """Client shards resident on device: upload, then gather per visit.
 
     Shards are concatenated along ONE flat sample axis — ``images``
-    ``(total, ...)``, ``labels`` ``(total,)`` — with an int32 ``offsets``
+    ``(total, D)``, ``labels`` ``(total,)`` — with an int32 ``offsets``
     table giving each client's first row: client ``r``'s sample ``i``
-    lives at ``offsets[r] + i``. Batch plans only ever index a client's
+    lives at ``offsets[r] + i``. Each sample is one contiguous feature row
+    of ``D = prod(item_shape)`` values (784 for MNIST shapes, 3,072 for
+    CIFAR's), flattened on the host before the upload, so a step's batch
+    is a bare gather of whole rows; ``item_shape`` records the clients'
+    per-sample shape. Batch plans only ever index a client's
     own ``[0, len)`` range, and the skewed shard sizes of the paper's
     non-IID partitions cost NO padding memory. After the upload
     (``nbytes``), the fused engine's per-visit H2D traffic is the int32
@@ -199,7 +204,7 @@ class DeviceDataPlane:
     the given fleet ids' shards upload, but ``offsets`` stays fleet-sized
     (``fleet_size``) with each visited id mapped to its cohort-local flat
     start — so the fleet-id ``rows`` arrays of ``stack_plan_indices`` and
-    the in-jit ``jnp.take`` gather are untouched by client virtualization.
+    the in-jit row gather are untouched by client virtualization.
     Unvisited (and ghost-padded) ids map to row 0: real data, only ever
     gathered under an all-invalid mask. Default (``None``) is the full
     fleet in id order — today's upload-once plane, bit-for-bit.
@@ -230,8 +235,10 @@ class DeviceDataPlane:
             fleet_size = len(clients)
         sizes = [len(c) for c in clients]
         real = sum(c.images.nbytes + c.labels.size * 4 for c in clients)
+        self.item_shape = tuple(clients[0].images.shape[1:])
+        d = math.prod(self.item_shape)
         if mesh is None:
-            imgs = np.concatenate([c.images for c in clients])
+            imgs = np.concatenate([c.images for c in clients]).reshape(-1, d)
             # int32 host-side so ``nbytes`` matches what actually crosses
             # H2D (jax demotes int64 on transfer when x64 is disabled)
             labs = np.concatenate([c.labels for c in clients]).astype(np.int32)
@@ -240,11 +247,10 @@ class DeviceDataPlane:
             from repro.launch.mesh import round_up_to_mesh
             n_max = max(sizes)
             k = round_up_to_mesh(len(clients), mesh, data_axis)
-            imgs = np.zeros((k * n_max,) + clients[0].images.shape[1:],
-                            clients[0].images.dtype)
+            imgs = np.zeros((k * n_max, d), clients[0].images.dtype)
             labs = np.zeros(k * n_max, np.int32)
             for i, c in enumerate(clients):
-                imgs[i * n_max: i * n_max + len(c)] = c.images
+                imgs[i * n_max: i * n_max + len(c)] = c.images.reshape(-1, d)
                 labs[i * n_max: i * n_max + len(c)] = c.labels
             starts = (np.arange(len(clients), dtype=np.int32) * n_max)
         offs = np.zeros(fleet_size, np.int32)

@@ -14,7 +14,7 @@ choice (``FLConfig.store``):
   for the block's **CohortArena**: a ``DeviceDataPlane`` over only the
   visited clients, with the fleet→cohort row remap folded into the
   plane's fleet-sized ``offsets`` table. Plans, the ``stack_plan_indices``
-  arrays and the in-jit ``jnp.take`` gather are identical to the device
+  arrays and the in-jit row gather are identical to the device
   store — the remap is invisible past the offsets table — so the two
   stores are bit-exact while peak device bytes scale with the cohort, not
   K. The previous block's arena is dropped when the next one is staged.

@@ -94,7 +94,7 @@ def test_fused_fedsr_block_compiles_for_v5e(one_chip):
           "lr": on_chip((n,)), "aggv": on_chip((n, C))}
     compiled = block.lower(
         jax.tree.map(lambda s: on_chip(s.shape, s.dtype), params), {},
-        on_chip((N, cfg.image_size, cfg.image_size, cfg.image_channels)),
+        on_chip((N, cfg.image_size * cfg.image_size * cfg.image_channels)),
         on_chip((N,), jnp.int32), on_chip((K,), jnp.int32), xs).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes

@@ -72,7 +72,9 @@ def test_device_data_plane_flat_layout():
     plane = DeviceDataPlane(clients)
     # unsharded: shards concatenate with NO padding (skewed non-IID shards
     # must not inflate device memory to K * N_max)
-    assert plane.images.shape == (25, 4, 4, 1)
+    # one contiguous feature row per sample, the client shape recorded
+    assert plane.images.shape == (25, 16)
+    assert plane.item_shape == (4, 4, 1)
     assert plane.labels.shape == (25,)
     assert plane.offsets.tolist() == [0, 5, 17]
     # client r's sample i lives at offsets[r] + i

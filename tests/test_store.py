@@ -32,7 +32,8 @@ def test_cohort_plane_offsets_table_keeps_fleet_ids():
     clients = _clients()                        # shard sizes 5, 12, 8, 3
     plane = DeviceDataPlane([clients[1], clients[3]],
                             client_ids=np.asarray([1, 3]), fleet_size=4)
-    assert plane.images.shape == (15, 4, 4, 1)  # 12 + 3 samples only
+    assert plane.images.shape == (15, 16)       # 12 + 3 samples only
+    assert plane.item_shape == (4, 4, 1)
     assert plane.offsets.shape == (4,)
     assert plane.offsets[1] == 0 and plane.offsets[3] == 12
     # unvisited ids point at 0 — a plan never addresses them in-block
