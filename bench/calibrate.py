@@ -51,15 +51,17 @@ def unchanged(cell):
 
 
 def half_batch(cell):
-    """Half of every batch left out, the mean taken over the rest."""
+    """Half of every batch left out, the mean taken over the rest: the hop
+    gather, where every loss on the fused path gets its batch, hands each
+    lane the first half of its rows."""
     from repro.core import local
-    loss = local.classifier_loss
+    gather = local.hop_gather
 
-    def half(params, batch, cfg):
-        n = batch["labels"].shape[0] // 2
-        return loss(params, {k: v[:n] for k, v in batch.items()}, cfg)
+    def half(images, labels, offsets, row_s, ix, item_shape):
+        return gather(images, labels, offsets, row_s,
+                      ix[:, :ix.shape[1] // 2], item_shape)
 
-    return _patch(local, "classifier_loss", half)
+    return _patch(local, "hop_gather", half)
 
 
 FAULTS = {f.__name__: f for f in (unchanged, half_batch)}

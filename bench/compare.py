@@ -9,8 +9,8 @@ def same_structure(params, cfg) -> None:
     names, shapes and dtypes of the program's model ``cfg``."""
     import jax
 
-    from repro.models.small import init_small_model
-    want = jax.eval_shape(lambda: init_small_model(jax.random.PRNGKey(0), cfg))
+    from repro.models.registry import init_for
+    want = jax.eval_shape(lambda: init_for(jax.random.PRNGKey(0), cfg))
     got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     if want != got:
         raise RuntimeError(f"benchmark weights {got} do not match the "

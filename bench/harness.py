@@ -13,6 +13,30 @@ per-layer metric module (``bench/metrics/<metric>.py``) has
 ``Outcome.record`` plus ``trace`` (a ``bench.trace.Trace``), ``window_s``,
 ``chips`` and ``peak`` (the device's row of ``peaks.json``); ``None``
 leaves the metric out of the line.
+
+Adding a cell. A new cell brings, as new files:
+
+* its configuration, ``bench/configs/<config>.json``: the sizes as run,
+  ``registry`` (the program's registry entry it starts from) and
+  ``program``, which maps each field of the program's ``ModelConfig`` that
+  the file changes to the key of the file that holds its value (see
+  ``model_config``; a file that changes nothing has no ``program``). Beside
+  it the counts ``bench/configs/<config>.py`` and the plain reference
+  ``bench/reference/<config>.py``, which read the same keys. It may hold a
+  ``tiny`` object, the CPU cut of its sizes;
+* its traffic mix, ``bench/traffic/<traffic>.json``, whose ``runner`` names
+  the runner, and which holds a ``tiny`` object: a partial copy of the
+  file, deep-merged over it for the CPU tests (``bench/tests/bench_tiny.py``),
+  which may only cut keys the file has. Runners never read ``tiny``;
+* where no runner or metric reader fits, ``bench/runners/<runner>.py`` and
+  ``bench/metrics/<metric>.py``;
+
+and, in BENCHMARK.json, its ``configs`` and ``workloads`` entries, its name
+appended to the ``workloads`` list of every end-to-end metric it reports
+(``setup_s`` has none: every cell reports it) and of every per-layer metric
+it should report. The CPU tests then run the cell at its tiny cut, its
+control and its planted faults (``bench/calibrate.py``) with no edit to an
+existing file.
 """
 from __future__ import annotations
 
@@ -94,6 +118,24 @@ class Cell:
     @property
     def runner(self):
         return load_module(BENCH / "runners" / f"{self.traffic['runner']}.py")
+
+
+def model_config(config: dict):
+    """The program's ``ModelConfig`` for a configuration file: the registry
+    entry the file names, with each field that the file's ``program`` maps
+    to one of its keys set from that key (a list as a tuple). A file that
+    states no change runs the registry's entry as it is."""
+    from repro.configs import get_config
+    base = get_config(config["registry"])
+    fields = {f.name for f in dataclasses.fields(base)}
+    changes = {}
+    for field, key in config.get("program", {}).items():
+        if field not in fields:
+            raise KeyError(f"configuration {config['name']!r}: the program's "
+                           f"ModelConfig has no field {field!r}")
+        value = config[key]
+        changes[field] = tuple(value) if isinstance(value, list) else value
+    return dataclasses.replace(base, **changes)
 
 
 def require_chips(n: int) -> None:

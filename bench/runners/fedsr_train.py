@@ -27,7 +27,11 @@ numbers that the traffic file gives a limit are compared:
 * ``dN_gap``: the same for the change over all ``check_blocks`` blocks;
 * ``dN_diff``: the change over all set-up blocks by the norm of the
   difference of the two changes, over the same denominator; the median
-  leaf. It is the number that sees a lower precision (PERF.md).
+  leaf. It is the number that sees a lower precision (PERF.md);
+* ``d1_diff``: the same for the first block's change, read before the
+  rounding of later blocks adds to it.
+
+Every run prints the readings on an earlier line of stdout.
 
 Leaves whose reference change is under a thousandth of the median leaf's
 are left out of all of them (none is, in these models).
@@ -40,7 +44,8 @@ import time
 import numpy as np
 
 from bench import compare, gen
-from bench.harness import BENCH, Check, Context, Outcome, load_module
+from bench.harness import (BENCH, Check, Context, Outcome, load_module,
+                           model_config)
 
 
 def _deployment(traffic: dict, seed: int):
@@ -54,7 +59,6 @@ def build(ctx: Context):
     import jax
     import jax.numpy as jnp
 
-    from repro.configs import get_config
     from repro.core.algorithms import make_algorithm
     from repro.core.comm import CommMeter
     from repro.core.local import LocalTrainer
@@ -66,7 +70,7 @@ def build(ctx: Context):
 
     cfgj, traffic = ctx.cell.config, ctx.cell.traffic
     data = traffic["data"]
-    cfg = get_config(cfgj["registry"])
+    cfg = model_config(cfgj)
     with ctx.phase("data"):
         (xtr, ytr), (xte, yte) = gen.image_task(ctx.seed, data)
         train = Dataset(gen.host_images(xtr, data), np.asarray(ytr),
@@ -182,6 +186,7 @@ def readings(w0, models, accs, ref_models, ref_accs) -> dict:
         "d1_gap": compare.norm_gap(w0, models[0], ref_models[0]),
         "dN_gap": compare.norm_gap(w0, models[-1], ref_models[-1]),
         "dN_diff": compare.median_diff(w0, models[-1], ref_models[-1]),
+        "d1_diff": compare.median_diff(w0, models[0], ref_models[0]),
     }
 
 
@@ -244,6 +249,7 @@ def run(ctx: Context) -> Outcome:
     if ctx.control:         # calibration: the one-pass reference in its place
         models, accs = reference_models(ctx, run_, checked, control=True)
     got = readings(w0, models, accs, ref_models, ref_accs)
+    ctx.emit(readings=got)
     lim = ctx.cell.traffic["limits"]
     return Outcome(metrics={"round_s": ctx.window_s / rounds},
                    attempted=blocks, failed=failed,

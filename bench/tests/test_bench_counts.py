@@ -1,5 +1,7 @@
 """The operation and byte counts of both configurations against hand
 counts, and the plain reference forward passes against the program's."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -62,6 +64,28 @@ def test_weights_and_reference_match_program(name):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     assert jnp.all(jnp.isfinite(got))
+
+
+@pytest.mark.parametrize("name", ["fedsr-cnn", "fedsr-mlp"])
+def test_model_config_is_the_registry_entry(name):
+    """A configuration that states no change runs the registry's entry,
+    field for field."""
+    from repro.configs import get_config
+    cfg, _ = _files(name)
+    assert "program" not in cfg
+    assert harness.model_config(cfg) == get_config(cfg["registry"])
+
+
+def test_model_config_takes_the_fields_the_file_maps():
+    from repro.configs import get_config
+    cfg, _ = _files("fedsr-mlp")
+    cfg = dict(cfg, mlp_hidden=[64, 32], program={"mlp_hidden": "mlp_hidden"})
+    got = harness.model_config(cfg)
+    assert got.mlp_hidden == (64, 32)
+    assert got == dataclasses.replace(get_config("fedsr-mlp"),
+                                      mlp_hidden=(64, 32))
+    with pytest.raises(KeyError, match="no field 'hidden'"):
+        harness.model_config(dict(cfg, program={"hidden": "mlp_hidden"}))
 
 
 def test_generator_is_seeded_and_shaped():

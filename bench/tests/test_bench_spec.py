@@ -4,7 +4,7 @@ import re
 
 import pytest
 
-from bench_tiny import ROOT, harness
+from bench_tiny import ROOT, harness, tiny_cell
 
 SPEC = harness.read_json(ROOT / "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -69,6 +69,18 @@ def test_workloads():
             v > 0 for v in traffic["limits"].values())
     four = sum(w["chips"] == 4 for w in cells)
     assert four <= max(1, len(cells) // 2)
+
+
+def test_every_traffic_file_a_cell_names_has_a_tiny_cut():
+    """The CPU tests cut each cell by its own files; no runner reads the
+    cut."""
+    for w in SPEC["workloads"]:
+        traffic = harness.read_json(harness.BENCH / "traffic"
+                                    / f"{w['traffic']}.json")
+        assert isinstance(traffic.get("tiny"), dict) and traffic["tiny"], w
+        tiny_cell(w["name"])
+    for runner in (harness.BENCH / "runners").glob("*.py"):
+        assert "tiny" not in runner.read_text(), runner
 
 
 @pytest.mark.parametrize("kind", ["end_to_end", "per_layer"])
